@@ -26,7 +26,8 @@ def _timed(fn, *args, **kw):
 
 def get_experiment(preset: str = "paper"):
     """Cached Experiment (data + pre-trained frozen DM)."""
-    from repro.configs.oscar import (DataConfig, DiffusionConfig, OscarConfig)
+    from repro.configs.oscar import (DataConfig, DiffusionConfig, OscarConfig,
+                                     paper_preset)
     if preset in _EXPERIMENT:
         return _EXPERIMENT[preset]
     if preset == "quick":
@@ -35,23 +36,8 @@ def get_experiment(preset: str = "paper"):
                             test_per_cat_dom=4),
             diffusion=DiffusionConfig(pretrain_steps=600, batch_size=64),
             classifier_steps=150)
-    else:  # "paper" scale (CPU-budgeted analogue of the paper's setting)
-        ocfg = OscarConfig(
-            # Data-starved clients: the paper's clients hold 30 images/cat
-            # of 224×224 NATURAL images — deeply data-poor relative to the
-            # task.  Our 16×16 procedural task is far simpler, so matching
-            # the paper's relative data poverty (Local weakest, DM-assisted
-            # methods strongest) needs proportionally fewer client images.
-            # The DM's knowledge is client-independent (the disjoint
-            # pretrain pool = Stable Diffusion's web-scale analogue).
-            data=DataConfig(num_categories=10, train_per_cat_dom=10,
-                            test_per_cat_dom=8,
-                            pretrain_pool_per_cat_dom=120),
-            diffusion=DiffusionConfig(d_model=144, pretrain_steps=6000,
-                                      batch_size=128),
-            classifier_steps=400,
-            # paper Table I uses the Table-III-optimal 30 samples/category
-            samples_per_category=30)
+    else:
+        ocfg = paper_preset()
     from repro.core.experiment import Experiment
     _EXPERIMENT[preset] = Experiment(ocfg)
     return _EXPERIMENT[preset]

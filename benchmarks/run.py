@@ -17,11 +17,14 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
+from repro.utils import enable_compile_cache
+
 ALL = ("kernels", "synthesis", "table4", "roofline", "table1", "table2",
        "table3", "fig1", "guidance", "dropout")
 
 
 def main():
+    enable_compile_cache(Path(__file__).resolve().parents[1])
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", default=os.environ.get("REPRO_BENCH_PRESET",
                                                        "paper"))

@@ -13,9 +13,11 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
 from repro.configs.oscar import DataConfig, DiffusionConfig, OscarConfig
 from repro.core.experiment import ALL_METHODS, Experiment
+from repro.utils import enable_compile_cache
 
 
 def main():
+    enable_compile_cache(Path(__file__).resolve().parents[1])
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", default="quick", choices=("quick", "paper"))
     ap.add_argument("--methods", default=",".join(ALL_METHODS))

@@ -14,9 +14,11 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
 from repro.configs.oscar import DataConfig, DiffusionConfig, OscarConfig
 from repro.core.experiment import Experiment
+from repro.utils import enable_compile_cache
 
 
 def main():
+    enable_compile_cache(Path(__file__).resolve().parents[1])
     ocfg = OscarConfig(
         data=DataConfig(num_categories=5, train_per_cat_dom=10,
                         test_per_cat_dom=5),

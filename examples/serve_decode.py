@@ -18,6 +18,7 @@ from repro.configs import get_config, smoke_config
 from repro.models.attention import KVCache
 from repro.models.transformer import forward, init_lm
 from repro.serve.steps import make_serve_step
+from repro.utils import enable_compile_cache
 
 
 def pad_kv(caches, max_len):
@@ -32,6 +33,7 @@ def pad_kv(caches, max_len):
 
 
 def main():
+    enable_compile_cache(Path(__file__).resolve().parents[1])
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-7b")
     ap.add_argument("--prompt-len", type=int, default=16)

@@ -35,6 +35,7 @@ from repro.configs.oscar import DiffusionConfig
 from repro.diffusion.dit import init_dit
 from repro.diffusion.schedule import make_schedule
 from repro.serve import SynthesisEngine, SynthesisService, SynthesisStore
+from repro.utils import enable_compile_cache
 
 DC = DiffusionConfig(d_model=64, num_layers=2, num_heads=2)
 H, STEPS, WAVE = 16, 8, 16
@@ -53,6 +54,7 @@ def encodings(n):
 
 
 def main():
+    enable_compile_cache(Path(__file__).resolve().parents[1])
     store_dir = Path(tempfile.mkdtemp(prefix="dsyn_store_"))
     enc = encodings(8)
 

@@ -17,6 +17,7 @@ import numpy as np
 
 from repro.configs import get_config, smoke_config
 from repro.train.steps import init_train_state, make_train_step
+from repro.utils import enable_compile_cache
 
 
 def synthetic_tokens(key, n_seq, seq, vocab):
@@ -32,6 +33,7 @@ def synthetic_tokens(key, n_seq, seq, vocab):
 
 
 def main():
+    enable_compile_cache(Path(__file__).resolve().parents[1])
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="xlstm-125m")
     ap.add_argument("--steps", type=int, default=200)

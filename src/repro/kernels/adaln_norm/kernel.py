@@ -24,24 +24,28 @@ def _adaln_kernel(x_ref, s_ref, b_ref, o_ref, *, eps):
     xc = x - mu
     var = jnp.mean(xc * xc, axis=-1, keepdims=True)
     y = xc * jax.lax.rsqrt(var + eps)
-    s = s_ref[0].astype(jnp.float32)                    # (d,)
+    s = s_ref[0].astype(jnp.float32)                    # (1, d)
     b = b_ref[0].astype(jnp.float32)
-    o_ref[0] = (y * (1.0 + s)[None] + b[None]).astype(o_ref.dtype)
+    o_ref[0] = (y * (1.0 + s) + b).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "interpret"))
 def adaln_norm_3d(x, scale, shift, *, eps: float = 1e-6,
                   interpret: bool = False):
-    """x: (B, N, d); scale/shift: (B, d)."""
+    """x: (B, N, d); scale/shift: (B, d).  The modulation rows go in as
+    (B, 1, d) with a (1, 1, d) block: Mosaic needs a block's last two
+    dims to be (8, 128)-divisible or equal to the array's, and a (1, d)
+    block of a (B, d) array is neither."""
     B, N, d = x.shape
     block = min(BLOCK_TOKENS, N)
     return pl.pallas_call(
         functools.partial(_adaln_kernel, eps=eps),
         grid=(B, pl.cdiv(N, block)),
         in_specs=[pl.BlockSpec((1, block, d), lambda b, i: (b, i, 0)),
-                  pl.BlockSpec((1, d), lambda b, i: (b, 0)),
-                  pl.BlockSpec((1, d), lambda b, i: (b, 0))],
+                  pl.BlockSpec((1, 1, d), lambda b, i: (b, 0, 0)),
+                  pl.BlockSpec((1, 1, d), lambda b, i: (b, 0, 0))],
         out_specs=pl.BlockSpec((1, block, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         interpret=interpret,
-    )(x, scale, shift)
+        name="adaln_norm",
+    )(x, scale[:, None, :], shift[:, None, :])

@@ -1,10 +1,11 @@
 """Public wrapper for the fused adaLN LayerNorm kernel: token-dim padding
-to the sublane multiple, CPU interpret fallback."""
+to the sublane multiple; interpret mode on the CPU
+(``repro.kernels.interpret_mode``)."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.adaln_norm import kernel as K
 
 
@@ -12,7 +13,7 @@ def adaln_norm(x, scale, shift, eps: float = 1e-6, *,
                interpret: bool | None = None):
     """x: (B, N, d) tokens; scale/shift: (B, d) per-batch-row modulation."""
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = interpret_mode()
     B, N, d = x.shape
     pad = (-N) % 8
     if pad:

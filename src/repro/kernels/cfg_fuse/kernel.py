@@ -113,6 +113,7 @@ def cfg_update_mixed_3d(x, eps_c, eps_u, noise, off, scal, *,
         ),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         interpret=interpret,
+        name="cfg_fuse_mixed",
     )(off, scal, x, eps_c, eps_u, noise)
 
 
@@ -148,6 +149,7 @@ def cfg_update_rowwise_3d(x, eps_c, eps_u, noise, off, scal, *,
         ),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         interpret=interpret,
+        name="cfg_fuse_rowwise",
     )(off, scal, x, eps_c, eps_u, noise)
 
 
@@ -170,4 +172,5 @@ def cfg_update_2d(x, eps_c, eps_u, noise, ab_t, ab_prev, *, s: float,
         ),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         interpret=interpret,
+        name="cfg_fuse",
     )(scal, x, eps_c, eps_u, noise)

@@ -1,16 +1,13 @@
 """jit'd public wrapper around the cfg_fuse Pallas kernel: handles
-flattening/padding to the (rows, 128) lane layout and CPU interpret mode."""
+flattening/padding to the (rows, 128) lane layout; interpret mode on
+the CPU (``repro.kernels.interpret_mode``)."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import interpret_mode
 from repro.kernels.cfg_fuse import kernel as K
-
-
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 def cfg_update(x, eps_c, eps_u, s, ab_t, ab_prev, noise, eta: float = 1.0,
@@ -18,7 +15,7 @@ def cfg_update(x, eps_c, eps_u, s, ab_t, ab_prev, noise, eta: float = 1.0,
     """Fused (1+s)·ε_c − s·ε_u guidance + ancestral update.  Shapes of
     x/eps_c/eps_u/noise are identical and arbitrary; s and eta are static."""
     if interpret is None:
-        interpret = _on_cpu()
+        interpret = interpret_mode()
     shape = x.shape
     n = int(np.prod(shape))
     rows = -(-n // K.LANES)
@@ -58,7 +55,7 @@ def cfg_update_rowwise(x, eps_c, eps_u, s, ab_t, ab_prev, noise, active,
     segment tables host-side and always uses the default
     ``row_offset=0``."""
     if interpret is None:
-        interpret = _on_cpu()
+        interpret = interpret_mode()
     shape = x.shape
     B = shape[0]
     n = int(np.prod(shape[1:]))
@@ -101,7 +98,7 @@ def cfg_update_mixed(x, eps_c, eps_u, mode, s, ab_t, ab_prev, noise, active,
     the same row-window contract applies: tensor row b reads scalar slot
     ``row_offset + b``, with the bounds check only for concrete offsets."""
     if interpret is None:
-        interpret = _on_cpu()
+        interpret = interpret_mode()
     shape = x.shape
     B = shape[0]
     n = int(np.prod(shape[1:]))

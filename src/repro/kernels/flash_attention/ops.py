@@ -1,22 +1,18 @@
-"""Public wrapper: (B, S, H, hd) layout, padding to block multiples, GQA,
-CPU interpret fallback."""
+"""Public wrapper: (B, S, H, hd) layout, padding to block multiples, GQA;
+interpret mode on the CPU (``repro.kernels.interpret_mode``)."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.flash_attention import kernel as K
-
-
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     softcap: float = 0.0, interpret: bool | None = None):
     """q: (B, Sq, Hq, hd); k/v: (B, Sk, Hkv, hd) → (B, Sq, Hq, hd)."""
     if interpret is None:
-        interpret = _on_cpu()
+        interpret = interpret_mode()
     B, Sq, Hq, hd = q.shape
     Sk = k.shape[1]
     qt = q.transpose(0, 2, 1, 3)

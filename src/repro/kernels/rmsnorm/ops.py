@@ -1,17 +1,17 @@
 """Public wrapper for the fused RMSNorm kernel: arbitrary leading dims,
-row padding, CPU interpret fallback."""
+row padding; interpret mode on the CPU (``repro.kernels.interpret_mode``)."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import interpret_mode
 from repro.kernels.rmsnorm import kernel as K
 
 
 def rmsnorm(x, scale, eps: float = 1e-6, *, interpret: bool | None = None):
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = interpret_mode()
     shape = x.shape
     d = shape[-1]
     rows = int(np.prod(shape[:-1]))

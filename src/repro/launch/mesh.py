@@ -14,14 +14,25 @@ Two mesh families:
   partitions the device set into the per-host submeshes
   (``host_submesh``) that ``serve/topology.py::HostTopology.from_mesh``
   places synthesis waves over.
+
+Every mesh here has ``Auto`` axes: the sharding rules and the serving
+path give shardings with ``NamedSharding`` and let XLA propagate them.
+``jax.make_mesh`` defaults to ``Explicit`` axes, under which reshapes
+such as the DiT's ``unpatchify`` of a row-sharded batch are refused.
 """
 from __future__ import annotations
 
 import numpy as np
 
 import jax
+from jax.sharding import AxisType, Mesh
 
 from repro.sharding.rules import MeshAxes
+
+
+def _auto_mesh(shape: tuple, axes: tuple) -> Mesh:
+    _validate_device_count(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def _validate_device_count(shape: tuple, axes: tuple):
@@ -43,8 +54,7 @@ def _validate_device_count(shape: tuple, axes: tuple):
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    _validate_device_count(shape, axes)
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_serving_mesh(*, hosts: int = 1, data: int = 1, model: int = 1):
@@ -54,9 +64,7 @@ def make_serving_mesh(*, hosts: int = 1, data: int = 1, model: int = 1):
     if min(hosts, data, model) < 1:
         raise ValueError(f"make_serving_mesh: hosts={hosts} data={data} "
                          f"model={model} must all be >= 1")
-    shape, axes = (hosts, data, model), ("hosts", "data", "model")
-    _validate_device_count(shape, axes)
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh((hosts, data, model), ("hosts", "data", "model"))
 
 
 def mesh_axes(mesh) -> MeshAxes:
@@ -70,8 +78,8 @@ def mesh_axes(mesh) -> MeshAxes:
 
 def host_submesh(mesh, host: int):
     """Host ``host``'s compute mesh: the ``hosts`` axis sliced away,
-    leaving that host's own (data, model) device block."""
-    from jax.sharding import Mesh
+    leaving that host's own (data, model) device block, with the serving
+    mesh's axis types."""
     if "hosts" not in mesh.axis_names:
         raise ValueError(
             f"mesh axes {mesh.axis_names} carry no 'hosts' axis — build "
@@ -82,10 +90,11 @@ def host_submesh(mesh, host: int):
                          f"serving mesh")
     axis = mesh.axis_names.index("hosts")
     devices = np.take(mesh.devices, host, axis=axis)
-    return Mesh(devices, tuple(n for n in mesh.axis_names if n != "hosts"))
+    keep = [i for i, n in enumerate(mesh.axis_names) if n != "hosts"]
+    return Mesh(devices, tuple(mesh.axis_names[i] for i in keep),
+                axis_types=tuple(mesh.axis_types[i] for i in keep))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over however many (host) devices exist — tests/benches."""
-    _validate_device_count((data, model), ("data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))

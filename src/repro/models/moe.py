@@ -203,16 +203,9 @@ def moe_ep(params, cfg: ModelConfig, x, par: Parallel, batch_sharded: bool = Tru
     # the VMA checker cannot infer that statically — disable the check for
     # that combine mode only.
     check = par.moe_combine != "reduce_scatter"
-    if hasattr(jax, "shard_map"):
-        fn = jax.shard_map(body, mesh=par.mesh, axis_names=all_axes,
-                           in_specs=tuple(specs), out_specs=(x_spec, P()),
-                           check_vma=check)
-    else:
-        # jax < 0.5: experimental API; all mesh axes are manual (== the
-        # all_axes set above) and the VMA checker is called check_rep
-        from jax.experimental.shard_map import shard_map
-        fn = shard_map(body, mesh=par.mesh, in_specs=tuple(specs),
-                       out_specs=(x_spec, P()), check_rep=check)
+    fn = jax.shard_map(body, mesh=par.mesh, axis_names=all_axes,
+                       in_specs=tuple(specs), out_specs=(x_spec, P()),
+                       check_vma=check)
     return fn(*args)
 
 

@@ -95,9 +95,11 @@ BIT-IDENTICAL regardless of host count, placement, or arrival order —
 and identical to a plain ``ragged=True`` engine serving the same
 requests.  Compaction composes per window: each host activation-sorts
 and epoch-plans its own window, so its segments stay contiguous
-row-windows of the wave table.  Multi-host is SIMULATED in one process
-(host partitions of the local device set); per-host device placement on
-a real pod hangs off ``HostTopology.mesh`` / ``host_submesh``.
+row-windows of the wave table.  All hosts run in one process.  A
+topology built from a serving mesh (``HostTopology.from_mesh``) places
+each host's window on its own submesh (``host_submesh``), with the
+denoiser's parameters replicated there once; a simulated topology runs
+every window on the default device.
 Under ragged scheduling EVERY mode places (classifier-guided and uncond
 rows ride the merged waves, so they shard by rows like any cfg row —
 the per-row correction batches the classifier over the window); in
@@ -373,6 +375,7 @@ class SynthesisEngine:
         # hits must be keyed per window, not pooled with _segment_geoms
         self._window_geoms: dict[tuple, set] = {}
         self._host_shardings: dict[int, Optional[dict]] = {}
+        self._host_params: dict[int, Any] = {}
         self._cache: dict[tuple, np.ndarray] = {}
         self._queue: list[SynthesisRequest] = []
         self._next_rid = 0
@@ -483,6 +486,7 @@ class SynthesisEngine:
                               # failed since the fleet was first threaded
         self.topology = topology
         self._host_shardings = {}
+        self._host_params = {}
         # counters from another layout cannot be merged: drop the old
         # breakdown, then materialize zeroed counters for every host so
         # the stats view (and the metrics dump) lists each one
@@ -1558,6 +1562,7 @@ class SynthesisEngine:
         self._check_fault("window", host=w.host, wave=wave)
         lo = w.offset
         sh = self._window_shardings(w.host)
+        params = self._window_params(w.host)
         x = jnp.zeros((0, self.image_size, self.image_size,
                        self.channels))
         prev = 0
@@ -1600,7 +1605,7 @@ class SynthesisEngine:
                                       rows=rows, begin=begin, end=end):
                     if mx is not None:
                         x = _window_segment_mixed(
-                            self.dm_params, self.dc, x, args["y"],
+                            params, self.dc, x, args["y"],
                             args["rk"], args["g"], args["ts"],
                             args["jloc"], args["ab_t"],
                             args["ab_prev"], args["act"],
@@ -1612,7 +1617,7 @@ class SynthesisEngine:
                             use_pallas=self.use_pallas)
                     else:
                         x = _window_segment(
-                            self.dm_params, self.dc, x, args["y"],
+                            params, self.dc, x, args["y"],
                             args["rk"], args["g"], args["ts"],
                             args["jloc"], args["ab_t"],
                             args["ab_prev"], args["act"],
@@ -1723,6 +1728,19 @@ class SynthesisEngine:
                   "labels": NamedSharding(sub, specs["labels"])}
         self._host_shardings[host] = sh
         return sh
+
+    def _window_params(self, host: int):
+        """The denoiser's parameters for host ``host``'s window segments:
+        replicated onto the host's compute mesh once per topology and
+        reused by every segment, so no dispatch moves them between
+        devices.  The engine's own copy for a simulated topology."""
+        sh = self._window_shardings(host)
+        if sh is None:
+            return self.dm_params
+        if host not in self._host_params:
+            self._host_params[host] = jax.device_put(
+                self.dm_params, NamedSharding(sh["y"].mesh, P()))
+        return self._host_params[host]
 
     def _fence_window(self, w, x, wave: int):
         """Fence ONE window's device output.  On a per-host worker the

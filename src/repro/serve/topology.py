@@ -204,7 +204,8 @@ class HostTopology:
         per = int(self.mesh.shape[lead]) // self.num_hosts
         idx = [slice(None)] * self.mesh.devices.ndim
         idx[axis] = slice(host * per, (host + 1) * per)
-        return Mesh(self.mesh.devices[tuple(idx)], self.mesh.axis_names)
+        return Mesh(self.mesh.devices[tuple(idx)], self.mesh.axis_names,
+                    axis_types=self.mesh.axis_types)
 
     def wave_quotas(self, wave_size: int) -> tuple:
         """Per-host row targets for one wave: ``wave_size`` split

@@ -2,6 +2,8 @@
 from __future__ import annotations
 
 import math
+import os
+from pathlib import Path
 from typing import Any, Callable, Iterable
 
 import jax
@@ -9,6 +11,22 @@ import jax.numpy as jnp
 import numpy as np
 
 PyTree = Any
+
+
+def enable_compile_cache(checkout: str | os.PathLike) -> str:
+    """Turn on JAX's persistent compilation cache for an entry point.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is changed here.  Otherwise the cache goes to the fixed
+    ``<checkout>/.jax_cache``: the directory is part of the cache key,
+    so it must not move between runs.  Returns the directory in use.
+    Entry points call this from ``main``; tests never do."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(Path(checkout).resolve() / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 # ---------------------------------------------------------------------------
