@@ -17,6 +17,11 @@ from repro.configs.oscar import DiffusionConfig
 from repro.utils import lecun_init, normal_init, zeros_init
 
 
+#: the named scope of each DiT sub-block, in the order a forward runs them
+DIT_SCOPES = ("dit.embed", "dit.mod", "dit.attn.qkv", "dit.attn.core",
+              "dit.attn.out", "dit.mlp", "dit.head")
+
+
 def timestep_embedding(t, dim: int, max_period: float = 10_000.0):
     half = dim // 2
     freqs = jnp.exp(-math.log(max_period) * jnp.arange(half) / half)
@@ -126,41 +131,51 @@ def dit_apply(params, dc: DiffusionConfig, x_t, t, y, *,
     B, H, W, C = x_t.shape
     p = dc.patch
     nh = heads or dc.num_heads
-    tok = _dense(params["patch_in"], patchify(x_t, p)) + params["pos"]
-
-    temb = timestep_embedding(t, dc.d_model)
-    c = _dense(params["t_mlp2"], jax.nn.silu(_dense(params["t_mlp1"], temb)))
-    if y is None:
-        y = jnp.broadcast_to(params["null_y"], (B, dc.cond_dim))
-    c = c + _dense(params["y_proj"], y.astype(jnp.float32))
-    c = jax.nn.silu(c)
-    # prepend the conditioning token (sliced off before unpatchify)
-    ytok = _dense(params["cond_tok"], y.astype(jnp.float32))[:, None, :]
-    tok = jnp.concatenate([ytok, tok], axis=1)
+    with jax.named_scope("dit.embed"):
+        tok = _dense(params["patch_in"], patchify(x_t, p)) + params["pos"]
+        temb = timestep_embedding(t, dc.d_model)
+        c = _dense(params["t_mlp2"],
+                   jax.nn.silu(_dense(params["t_mlp1"], temb)))
+        if y is None:
+            y = jnp.broadcast_to(params["null_y"], (B, dc.cond_dim))
+        c = c + _dense(params["y_proj"], y.astype(jnp.float32))
+        c = jax.nn.silu(c)
+        # prepend the conditioning token (sliced off before unpatchify)
+        ytok = _dense(params["cond_tok"], y.astype(jnp.float32))[:, None, :]
+        tok = jnp.concatenate([ytok, tok], axis=1)
 
     d = dc.d_model
     hd = d // nh
     for blk in params["blocks"]:
-        mod = _dense(blk["mod"], c)                       # (B, 6d)
-        sa_shift, sa_scale, sa_gate, ml_shift, ml_scale, ml_gate = jnp.split(mod, 6, -1)
-        h = _modulated_ln(tok, sa_scale, sa_shift, fused)
-        qkv = _dense_act(blk["wqkv"], h, bf16).reshape(B, -1, 3, nh, hd)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        if fused:
-            from repro.kernels.flash_attention import ops as fa_ops
-            o = fa_ops.flash_attention(q, k, v, causal=False).reshape(B, -1, d)
-        else:
-            logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * hd ** -0.5
-            attn = jax.nn.softmax(logits, axis=-1)
-            o = jnp.einsum("bhqk,bkhd->bqhd", attn, v).reshape(B, -1, d)
-        tok = tok + sa_gate[:, None] * _dense_act(blk["wo"], o, bf16)
-        h = _modulated_ln(tok, ml_scale, ml_shift, fused)
-        h = _dense_act(blk["w_down"],
-                       jax.nn.gelu(_dense_act(blk["w_up"], h, bf16)), bf16)
-        tok = tok + ml_gate[:, None] * h
+        with jax.named_scope("dit.mod"):
+            mod = _dense(blk["mod"], c)                   # (B, 6d)
+            (sa_shift, sa_scale, sa_gate,
+             ml_shift, ml_scale, ml_gate) = jnp.split(mod, 6, -1)
+        with jax.named_scope("dit.attn.qkv"):
+            h = _modulated_ln(tok, sa_scale, sa_shift, fused)
+            qkv = _dense_act(blk["wqkv"], h, bf16).reshape(B, -1, 3, nh, hd)
+            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        with jax.named_scope("dit.attn.core"):
+            if fused:
+                from repro.kernels.flash_attention import ops as fa_ops
+                o = fa_ops.flash_attention(q, k, v, causal=False)
+            else:
+                logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * hd ** -0.5
+                attn = jax.nn.softmax(logits, axis=-1)
+                o = jnp.einsum("bhqk,bkhd->bqhd", attn, v)
+            o = o.reshape(B, -1, d)
+        with jax.named_scope("dit.attn.out"):
+            tok = tok + sa_gate[:, None] * _dense_act(blk["wo"], o, bf16)
+        with jax.named_scope("dit.mlp"):
+            h = _modulated_ln(tok, ml_scale, ml_shift, fused)
+            h = _dense_act(blk["w_down"],
+                           jax.nn.gelu(_dense_act(blk["w_up"], h, bf16)),
+                           bf16)
+            tok = tok + ml_gate[:, None] * h
 
-    tok = tok[:, 1:]   # drop the conditioning token
-    shift, scale = jnp.split(_dense(params["out_mod"], c), 2, -1)
-    tok = _modulated_ln(tok, scale, shift, fused)
-    eps = _dense(params["patch_out"], tok)
-    return unpatchify(eps, p, H, W, C)
+    with jax.named_scope("dit.head"):
+        tok = tok[:, 1:]   # drop the conditioning token
+        shift, scale = jnp.split(_dense(params["out_mod"], c), 2, -1)
+        tok = _modulated_ln(tok, scale, shift, fused)
+        eps = _dense(params["patch_out"], tok)
+        return unpatchify(eps, p, H, W, C)

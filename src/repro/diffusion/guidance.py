@@ -17,6 +17,10 @@ A strategy answers two questions per step:
   plain ancestral step — bit-identical to the historical samplers.
 * ``prepare(params, dc) -> aux`` — per-trajectory precompute hoisted out
   of the scan (e.g. the stacked cond/uncond conditioning batch).
+
+Every scan body runs under the ``sampler.step`` named scope: in a
+profiler trace the guidance combine, noise draw and update sit there,
+and the denoiser's ops under its own ``dit.*`` scopes inside it.
 """
 from __future__ import annotations
 
@@ -31,6 +35,19 @@ import numpy as np
 from repro.configs.oscar import DiffusionConfig
 from repro.diffusion.dit import dit_apply
 from repro.diffusion.schedule import NoiseSchedule
+
+
+SAMPLER_SCOPE = "sampler.step"
+
+
+def _scan_body(step):
+    """``step`` under the ``sampler.step`` named scope (op metadata
+    only)."""
+    @functools.wraps(step)
+    def body(*args):
+        with jax.named_scope(SAMPLER_SCOPE):
+            return step(*args)
+    return body
 
 
 def _strictly_decreasing(ts, num_steps: int):
@@ -180,6 +197,7 @@ def reverse_sample(params, dc: DiffusionConfig, sched: NoiseSchedule,
     x = jax.random.normal(k0, (B, H, H, channels))
     aux = strategy.prepare(params, dc)
 
+    @_scan_body
     def step(carry, inp):
         x, key = carry
         t, abt, abp = inp
@@ -278,6 +296,7 @@ def _ragged_scan(params, dc: DiffusionConfig, x, y2, row_keys, guidance,
     full trajectory)."""
     B, H, _, channels = x.shape
 
+    @_scan_body
     def step(x, inp):
         t, abt, abp, j = inp                     # (B,) each
         active = j >= 0
@@ -369,6 +388,7 @@ def _ragged_scan_window(params, dc: DiffusionConfig, x, y2, row_keys,
     UNCLIPPED."""
     B, H, _, channels = x.shape
 
+    @_scan_body
     def step(x, inp):
         t, j, abt, abp, act = inp         # t/j: (Bw,); abt/abp/act: (B,)
         x2 = jnp.concatenate([x, x], axis=0)
@@ -502,6 +522,7 @@ def _mixed_scan(params, dc: DiffusionConfig, x, y2, row_keys, guidance, mode,
     mode = jnp.asarray(mode, jnp.float32)
     is_clf = mode >= 0.5
 
+    @_scan_body
     def step(x, inp):
         t, abt, abp, j = inp                     # (B,) each
         active = j >= 0
@@ -570,6 +591,7 @@ def _mixed_scan_window(params, dc: DiffusionConfig, x, y2, row_keys,
     is_clf_w = sl(mode) >= 0.5
     g_w = sl(guidance)
 
+    @_scan_body
     def step(x, inp):
         t, j, abt, abp, act = inp         # t/j: (Bw,); abt/abp/act: (B,)
         x2 = jnp.concatenate([x, x], axis=0)

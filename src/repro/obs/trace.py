@@ -35,6 +35,14 @@ one lock so no record is lost.  The disabled path is untouched:
 ``span()`` still returns the shared no-op and ``stamp`` still returns
 before reading any clock or taking any lock.
 
+PROFILER TRACE: an enabled tracer also opens a
+``jax.profiler.TraceAnnotation`` named ``synth.<span name>`` for every
+span, its attributes (``wave``, ``host``, ``rows``, ``mode`` ...) as the
+annotation's arguments, so under ``jax.profiler.start_trace`` the
+engine's spans sit on the profiler's clock beside the device ops they
+dispatch and fence, and the spans of one wave join by ``wave``.  With
+no profiler session active the annotation records nothing.
+
 Export to a Perfetto/``chrome://tracing``-loadable timeline lives in
 ``obs/export.py``.
 """
@@ -45,10 +53,15 @@ import time
 from dataclasses import dataclass, field
 from typing import Callable, Optional
 
+from jax.profiler import TraceAnnotation
+
 #: request-lifecycle stages, in order.  ``stamp`` accepts only these.
 LIFECYCLE_STAGES = ("admit", "enqueue", "pack", "dispatch", "retire",
                     "deliver")
 _STAGE_SET = frozenset(LIFECYCLE_STAGES)
+
+#: what an enabled tracer's spans are called in a ``jax.profiler`` trace
+PROFILER_PREFIX = "synth."
 
 
 class FakeClock:
@@ -111,27 +124,36 @@ NULL_SPAN = _NullSpan()
 
 class _OpenSpan:
     """A span being recorded; closes (and appends to the tracer) on
-    ``__exit__``."""
-    __slots__ = ("_tracer", "name", "attrs", "_start", "depth")
+    ``__exit__``.  While open it is also a ``TraceAnnotation`` in any
+    active profiler trace."""
+    __slots__ = ("_tracer", "name", "attrs", "_start", "depth", "_note")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
         self.name = name
         self.attrs = attrs
+        self._note = None
 
     def set(self, **attrs):
         self.attrs.update(attrs)
+        if self._note is not None:
+            self._note.set_metadata(**attrs)
         return self
 
     def __enter__(self):
         stack = self._tracer._stack   # this THREAD's nesting stack
         self.depth = len(stack)
         stack.append(self)
+        self._note = TraceAnnotation(PROFILER_PREFIX + self.name,
+                                     **self.attrs)
+        self._note.__enter__()
         self._start = self._tracer.clock()
         return self
 
     def __exit__(self, *exc):
         end = self._tracer.clock()
+        self._note.__exit__(*exc)
+        self._note = None
         stack = self._tracer._stack
         if stack and stack[-1] is self:
             stack.pop()

@@ -191,6 +191,15 @@ def _encoding_hash(encoding: np.ndarray) -> str:
                         .tobytes()).hexdigest()
 
 
+@jax.jit
+def _wave_row_keys(key, rids, ridx):
+    """``fold_in(fold_in(key, rid), row)`` for each row of a wave: one
+    program under a stable name in the device trace."""
+    return jax.vmap(
+        lambda r, i: jax.random.fold_in(jax.random.fold_in(key, r), i)
+    )(rids, ridx)
+
+
 @dataclass
 class SynthesisRequest:
     rid: int
@@ -772,11 +781,9 @@ class SynthesisEngine:
         row_index)`` — a function of the row's identity, NOT its wave
         position or schedule, so ragged and compacted waves (and any
         packing of either) draw identical streams for the same row."""
-        rids = jnp.asarray([m[2] for m in meta], jnp.uint32)
-        ridx = jnp.asarray([m[3] for m in meta], jnp.uint32)
-        return jax.vmap(
-            lambda r, i: jax.random.fold_in(jax.random.fold_in(key, r), i)
-        )(rids, ridx)
+        return _wave_row_keys(key,
+                              np.asarray([m[2] for m in meta], np.uint32),
+                              np.asarray([m[3] for m in meta], np.uint32))
 
     def _sample_wave_compacted(self, cond_rows, meta, key, max_steps: int):
         """One merged classifier-free wave, iteration-compacted: rows
@@ -945,9 +952,15 @@ class SynthesisEngine:
             live = sorted(g for g, q in st.groups.items()
                           if q.rows_available())
             if not live:
-                if polling and self._poll_all(poll, host_polls):
-                    self._admit_new(st, results)
-                    continue
+                if polling:
+                    # the queues ran dry: admit only while a hook keeps
+                    # the drain alive
+                    with self.tracer.span("wave.admit", wave=st.wave_i):
+                        more = self._poll_all(poll, host_polls)
+                        if more:
+                            self._admit_new(st, results)
+                    if more:
+                        continue
                 break
             grp = st.groups[live[0]]
             try:
@@ -1211,20 +1224,22 @@ class SynthesisEngine:
         while True:
             # admission runs at every wave boundary with or without a
             # poll, so requests submitted by another thread while waves
-            # are in flight stream into this drain too
-            self._poll_all(poll, host_polls)
-            self._admit_new(st, results)
-            parts = q.take(wave_rows)
-            got = sum(t for _, t, _ in parts)
-            if got == 0:
-                break
-            if got < wave_rows:
-                # open wave: give late arrivals one chance to fill it
+            # are in flight stream into this drain too; the span covers
+            # taking the wave's rows off the queue
+            with self.tracer.span("wave.admit", wave=st.wave_i):
                 self._poll_all(poll, host_polls)
                 self._admit_new(st, results)
-                more = q.take(wave_rows - got)
-                parts += more
-                got += sum(t for _, t, _ in more)
+                parts = q.take(wave_rows)
+                got = sum(t for _, t, _ in parts)
+                if 0 < got < wave_rows:
+                    # open wave: give late arrivals one chance to fill it
+                    self._poll_all(poll, host_polls)
+                    self._admit_new(st, results)
+                    more = q.take(wave_rows - got)
+                    parts += more
+                    got += sum(t for _, t, _ in more)
+            if got == 0:
+                break
             # tail: snapshot keeps the group-uniform shape, streaming
             # rounds to a granule multiple (one extra compiled tail shape)
             target = (-(-got // self.granule) * self.granule if stream
@@ -1258,9 +1273,8 @@ class SynthesisEngine:
                         # key, same cond — a discarded bit-identical copy
                         # that can never perturb the real rows
                         meta += [meta[-1]] * (target - got)
-            for p, _, _ in parts:
-                self.tracer.stamp(p.req.rid, "pack")
-            kw = jax.random.fold_in(key, st.wave_i)
+                for p, _, _ in parts:
+                    self.tracer.stamp(p.req.rid, "pack")
             st.wave_i += 1
             with self.tracer.span("wave.dispatch", wave=st.wave_i - 1,
                                   host=0, rows=target,
@@ -1293,17 +1307,18 @@ class SynthesisEngine:
                     self.metrics.inc("row_iters_active", active_iters)
                     sp.set(iters_scheduled=sched_iters)
                 else:
-                    x = self._sample_wave(q.head, rows, kw)
+                    x = self._sample_wave(
+                        q.head, rows, jax.random.fold_in(key, st.wave_i - 1))
                     self.metrics.inc("row_iters_scheduled",
                                      target * q.head.num_steps)
                     self.metrics.inc("row_iters_active",
                                      got * q.head.num_steps)
-            for p, _, _ in parts:
-                self.tracer.stamp(p.req.rid, "dispatch")
-            self.metrics.inc("waves")
-            self.metrics.inc("generated", got)
-            self.metrics.inc("scheduled_rows", target)
-            self.metrics.inc("padded", target - got)
+                for p, _, _ in parts:
+                    self.tracer.stamp(p.req.rid, "dispatch")
+                self.metrics.inc("waves")
+                self.metrics.inc("generated", got)
+                self.metrics.inc("scheduled_rows", target)
+                self.metrics.inc("padded", target - got)
             if inflight is not None:
                 self._retire(st, results, *inflight)
             if self.async_waves:
@@ -1346,22 +1361,24 @@ class SynthesisEngine:
             # through the same proportional split (failover == re-quota)
             topo = self.topology
             quotas = topo.wave_quotas(wave_target)
-            self._poll_all(poll, host_polls)
-            self._admit_new(st, results)
-            parts_h = [q.take(quotas[h]) for h, q in enumerate(grp.queues)]
-            got = sum(t for parts in parts_h for _, t, _ in parts)
-            if got == 0:
-                break
-            if got < sum(quotas):
-                # open wave: give late arrivals one chance to fill the
-                # hosts' windows before padding them
+            with self.tracer.span("wave.admit", wave=st.wave_i):
                 self._poll_all(poll, host_polls)
                 self._admit_new(st, results)
-                for h, q in enumerate(grp.queues):
-                    have = sum(t for _, t, _ in parts_h[h])
-                    if have < quotas[h]:
-                        parts_h[h] += q.take(quotas[h] - have)
+                parts_h = [q.take(quotas[h])
+                           for h, q in enumerate(grp.queues)]
                 got = sum(t for parts in parts_h for _, t, _ in parts)
+                if 0 < got < sum(quotas):
+                    # open wave: give late arrivals one chance to fill
+                    # the hosts' windows before padding them
+                    self._poll_all(poll, host_polls)
+                    self._admit_new(st, results)
+                    for h, q in enumerate(grp.queues):
+                        have = sum(t for _, t, _ in parts_h[h])
+                        if have < quotas[h]:
+                            parts_h[h] += q.take(quotas[h] - have)
+                    got = sum(t for parts in parts_h for _, t, _ in parts)
+            if got == 0:
+                break
             rows_h = [sum(t for _, t, _ in parts) for parts in parts_h]
             placement = WavePlacement.plan(rows_h, topo.granules)
             geom = tuple((w.host, w.rows) for w in placement.windows)
@@ -1747,7 +1764,8 @@ class SynthesisEngine:
         ``device.scan`` span measures that host's own device time — not
         another host's serialized wait, which is what the old in-order
         fence loop silently recorded for every window after the first."""
-        with self.tracer.span("device.scan", host=w.host, rows=w.rows):
+        with self.tracer.span("device.scan", host=w.host, wave=wave,
+                              rows=w.rows):
             if self._sync_hook is not None:
                 self._sync_hook("fence", w.host, wave)
             self._fence(x, host=w.host, wave=wave)
@@ -1768,31 +1786,35 @@ class SynthesisEngine:
         else:
             for w, x in zip(wins, xs):
                 self._fence_window(w, x, wave)
-        for w, x, inv in zip(placement.windows, xs, invs):
-            arr = np.asarray(x)
-            if inv is not None:
-                arr = arr[inv]
-            outs = arr[:w.real]
+        with self.tracer.span("wave.retire", wave=wave):
+            for w, x, inv in zip(placement.windows, xs, invs):
+                arr = np.asarray(x)
+                if inv is not None:
+                    arr = arr[inv]
+                outs = arr[:w.real]
+                off = 0
+                for p, t, _ in parts_h[w.host]:
+                    p.chunks.append(outs[off:off + t])
+                    off += t
+                    if p.done_rows() == p.fresh:
+                        self._finalize(st, p, results)
+
+    def _retire(self, st: "_DrainState", results, x, parts, n_real,
+                wave: int = -1):
+        """Fence on the wave's device computation (``device.scan``), then
+        (``wave.retire``) scatter rows back to their requests and finalize
+        any request whose rows are complete."""
+        with self.tracer.span("device.scan", host=0, wave=wave,
+                              rows=int(x.shape[0])):
+            self._fence(x, host=0, wave=wave)
+        with self.tracer.span("wave.retire", wave=wave, host=0):
+            outs = np.asarray(x)[:n_real]
             off = 0
-            for p, t, _ in parts_h[w.host]:
+            for p, t, _ in parts:
                 p.chunks.append(outs[off:off + t])
                 off += t
                 if p.done_rows() == p.fresh:
                     self._finalize(st, p, results)
-
-    def _retire(self, st: "_DrainState", results, x, parts, n_real,
-                wave: int = -1):
-        """Fence on the wave's device computation, scatter rows back to
-        their requests, finalize any request whose rows are complete."""
-        with self.tracer.span("device.scan", host=0, rows=int(x.shape[0])):
-            self._fence(x, host=0, wave=wave)
-        outs = np.asarray(x)[:n_real]
-        off = 0
-        for p, t, _ in parts:
-            p.chunks.append(outs[off:off + t])
-            off += t
-            if p.done_rows() == p.fresh:
-                self._finalize(st, p, results)
 
     def _finalize(self, st: "_DrainState", p: _Pending, results):
         self.tracer.stamp(p.req.rid, "retire")

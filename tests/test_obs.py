@@ -336,3 +336,76 @@ def test_disabled_tracer_stays_nullspan_under_threads():
     for t in threads:
         t.join()
     assert not reads and not tr.spans and not tr.lifecycle
+
+
+# ---------------------------------------------------------------------------
+# the denoiser's named scopes: metadata the device trace reads, never values
+# ---------------------------------------------------------------------------
+
+def _op_names(compiled_text: str) -> set[str]:
+    import re
+    return set(re.findall(r'op_name="([^"]*)"', compiled_text))
+
+
+def _dit_fn(use_pallas: bool = False):
+    from repro.diffusion.dit import dit_apply
+    return jax.jit(lambda p, x, t, y: dit_apply(p, DC, x, t, y,
+                                                use_pallas=use_pallas))
+
+
+def test_dit_scopes_in_hlo_metadata_and_bit_identical(dm, monkeypatch):
+    """Every sub-block scope is in the compiled program's ``op_name``
+    metadata, and the output is bit-identical to the same program traced
+    with every scope taken out."""
+    import contextlib
+
+    from repro.diffusion.dit import DIT_SCOPES
+    params, _ = dm
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, H, H, 3))
+    t = jax.numpy.array([3, 11])
+    y = jax.numpy.asarray(np.stack([_enc(1), _enc(2)]))
+    scoped = _dit_fn()
+    names = _op_names(scoped.lower(params, x, t, y).compile().as_text())
+    parts = {part for n in names for part in n.split("/")}
+    assert set(DIT_SCOPES) <= parts
+    out = scoped(params, x, t, y)
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = _dit_fn()
+    assert not {p for n in _op_names(plain.lower(params, x, t, y).compile()
+                                     .as_text())
+                for p in n.split("/")} & set(DIT_SCOPES)
+    assert np.array_equal(np.asarray(out), np.asarray(plain(params, x, t, y)))
+
+
+def test_flash_attention_sits_in_the_attention_core_scope(dm):
+    """The fused path's kernel call runs under ``dit.attn.core``, the
+    scope the naive score/softmax/PV chain runs under."""
+    import re
+    params, _ = dm
+    x = jax.numpy.zeros((2, H, H, 3))
+    t = jax.numpy.array([1, 2])
+    y = jax.numpy.asarray(np.stack([_enc(1), _enc(2)]))
+    text = _dit_fn(use_pallas=True).lower(params, x, t, y).as_text(
+        debug_info=True)
+    locs = set(re.findall(r'loc\("([^"]*)"', text))
+    assert any(n.endswith("dit.attn.core/jit(flash_attention_bhsd)")
+               for n in locs)
+
+
+def test_wave_program_scan_body_scoped(dm):
+    """The ragged wave program's scan body runs under ``sampler.step``,
+    the denoiser's ops inside it under their own scopes."""
+    from repro.diffusion.sampler import _ragged_core
+    from repro.diffusion.guidance import ragged_tables
+    params, sched = dm
+    steps = np.array([3, 2], np.int32)
+    ts, ab_t, ab_prev, jloc = ragged_tables(sched, steps, 3)
+    keys = jax.random.split(jax.random.PRNGKey(0), 2)
+    y = jax.numpy.asarray(np.stack([_enc(1), _enc(2)]))
+    names = _op_names(_ragged_core.lower(
+        params, DC, y, keys, jax.numpy.array([1.0, 2.0]), ts, ab_t, ab_prev,
+        jloc, image_size=H, channels=3, eta=1.0, use_pallas=False)
+        .compile().as_text())
+    assert any("sampler.step/dit.attn.core/" in n for n in names)
+    assert any("sampler.step/" in n and "/dit." not in n for n in names)
