@@ -52,7 +52,8 @@ class DiffusionConfig:
     # Pallas flash-attention kernel and its three LN+modulation sites
     # through kernels/adaln_norm.  fp32 fused output matches the naive
     # denoiser within float tolerance (online softmax reorders sums);
-    # the default (False) path stays bit-exact with prior releases.
+    # the default (False) path keeps the einsum chain, except on the TPU
+    # for 128 <= S <= 1025 tokens, where it runs kernels/dit_attention.
     use_pallas: bool = False
     # Under the fused path only: run the QKV/MLP matmuls with bf16
     # activations and fp32 accumulation (MXU-native mixed precision).

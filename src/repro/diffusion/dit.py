@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.oscar import DiffusionConfig
+from repro.kernels.dit_attention import ops as da_ops
+from repro.kernels.dit_attention import ref as da_ref
 from repro.utils import lecun_init, normal_init, zeros_init
 
 
@@ -101,6 +103,21 @@ def _modulated_ln(x, scale, shift, fused: bool):
     return _ln(x) * (1 + scale[:, None]) + shift[:, None]
 
 
+def _attention_core(qkv, heads: int, fused: bool):
+    """Non-causal attention of one block: the QKV product (B, S, 3d) →
+    (B, S, d), by the kernel the path and the shape call for."""
+    if fused:
+        from repro.kernels.flash_attention import ops as fa_ops
+        B, S, _ = qkv.shape
+        qkv = qkv.reshape(B, S, 3, heads, -1)
+        o = fa_ops.flash_attention(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2],
+                                   causal=False)
+        return o.reshape(B, S, -1)
+    if da_ops.engages(qkv.shape[1]):
+        return da_ops.dit_attention(qkv, heads)
+    return da_ref.dit_attention(qkv, heads)
+
+
 def patchify(x, p: int):
     B, H, W, C = x.shape
     x = x.reshape(B, H // p, p, W // p, p, C)
@@ -120,12 +137,20 @@ def dit_apply(params, dc: DiffusionConfig, x_t, t, y, *,
     """ε-prediction.  x_t: (B,H,W,C); t: (B,) int32; y: (B, cond_dim) or
     None (→ null embedding Ø).
 
-    ``use_pallas`` (or ``dc.use_pallas``) swaps the attention einsum chain
-    for ``kernels.flash_attention`` (non-causal, S = n_tok+1) and the three
+    The default path runs each block's attention core as the einsum chain
+    of ``kernels.dit_attention.ref``, except where
+    ``kernels.dit_attention.ops.engages`` says the kernel applies (on the
+    TPU, for ``MIN_SEQ`` ≤ S ≤ ``MAX_SEQ``).  There the core is the
+    ``dit_attention`` kernel, whose products take the bfloat16 operands
+    XLA gives the chain at the default matmul precision, and which keeps
+    the S×S scores in VMEM.  On the CPU the default path is the chain.
+
+    ``use_pallas`` (or ``dc.use_pallas``) swaps the attention core for
+    ``kernels.flash_attention`` (non-causal, S = n_tok+1) and the three
     LN+modulation sites for ``kernels.adaln_norm``; fp32 output matches the
     naive path within float tolerance.  ``dc.bf16_act`` additionally runs
     the QKV/MLP matmuls with bf16 activations + fp32 accumulation (fused
-    path only).  The default path is untouched and stays bit-exact."""
+    path only)."""
     fused = use_pallas or getattr(dc, "use_pallas", False)
     bf16 = fused and getattr(dc, "bf16_act", False)
     B, H, W, C = x_t.shape
@@ -144,8 +169,6 @@ def dit_apply(params, dc: DiffusionConfig, x_t, t, y, *,
         ytok = _dense(params["cond_tok"], y.astype(jnp.float32))[:, None, :]
         tok = jnp.concatenate([ytok, tok], axis=1)
 
-    d = dc.d_model
-    hd = d // nh
     for blk in params["blocks"]:
         with jax.named_scope("dit.mod"):
             mod = _dense(blk["mod"], c)                   # (B, 6d)
@@ -153,17 +176,9 @@ def dit_apply(params, dc: DiffusionConfig, x_t, t, y, *,
              ml_shift, ml_scale, ml_gate) = jnp.split(mod, 6, -1)
         with jax.named_scope("dit.attn.qkv"):
             h = _modulated_ln(tok, sa_scale, sa_shift, fused)
-            qkv = _dense_act(blk["wqkv"], h, bf16).reshape(B, -1, 3, nh, hd)
-            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+            qkv = _dense_act(blk["wqkv"], h, bf16)
         with jax.named_scope("dit.attn.core"):
-            if fused:
-                from repro.kernels.flash_attention import ops as fa_ops
-                o = fa_ops.flash_attention(q, k, v, causal=False)
-            else:
-                logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * hd ** -0.5
-                attn = jax.nn.softmax(logits, axis=-1)
-                o = jnp.einsum("bhqk,bkhd->bqhd", attn, v)
-            o = o.reshape(B, -1, d)
+            o = _attention_core(qkv, nh, fused)
         with jax.named_scope("dit.attn.out"):
             tok = tok + sa_gate[:, None] * _dense_act(blk["wo"], o, bf16)
         with jax.named_scope("dit.mlp"):

@@ -1,5 +1,6 @@
 """Pallas kernels of the fused denoiser path (``cfg_fuse``,
-``flash_attention``, ``adaln_norm``) plus ``rmsnorm``.
+``flash_attention``, ``adaln_norm``), the default path's attention core
+on the TPU (``dit_attention``), plus ``rmsnorm``.
 
 Each family has ``kernel.py`` (the ``pallas_call``, named after the
 family), ``ops.py`` (the public wrapper) and ``ref.py`` (the jnp oracle).

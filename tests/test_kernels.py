@@ -6,6 +6,8 @@ import pytest
 
 from repro.kernels.cfg_fuse import ops as cfg_ops
 from repro.kernels.cfg_fuse import ref as cfg_ref
+from repro.kernels.dit_attention import ops as da_ops
+from repro.kernels.dit_attention import ref as da_ref
 from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.flash_attention import ref as fa_ref
 from repro.kernels.rmsnorm import ops as rn_ops
@@ -442,3 +444,46 @@ def test_adaln_norm_per_row_modulation(rng_key):
     perm = jnp.array([2, 0, 1])
     out_p = an_ops.adaln_norm(x3, scale[perm], shift[perm])
     assert jnp.array_equal(out[perm], out_p)
+
+
+# ---------------------------------------------------------------------------
+# DiT attention core (kernels/dit_attention)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("B,S,heads,hd", [
+    (2, 257, 12, 64),   # DiT-B/2: 16x16 patch grid + cond token
+    (2, 65, 4, 36),     # 8x8 patch grid + cond token
+    (3, 17, 4, 36),     # the paper preset's 4x4 grid + cond token
+])
+def test_dit_attention_matches_einsum_chain(rng_key, B, S, heads, hd):
+    """Forward: the kernel against the chain on the same bfloat16-rounded
+    QKV product; what is left is p's bfloat16 cast before the PV product
+    (2^-9 relative), so the gap is at most 2^-9·max|v|, here allowed
+    twice that.  Backward: the custom VJP is the chain's VJP, so the
+    gradients with respect to the (B, S, 3d) input are equal."""
+    ks = jax.random.split(rng_key, 2)
+    qkv = jax.random.normal(ks[0], (B, S, 3 * heads * hd))
+    out = jax.jit(da_ops.dit_attention, static_argnums=1)(qkv, heads)
+    assert out.shape == (B, S, heads * hd) and out.dtype == jnp.float32
+    rounded = qkv.astype(jnp.bfloat16).astype(jnp.float32)
+    ref = da_ref.dit_attention(rounded, heads)
+    assert jnp.max(jnp.abs(out - ref)) <= 2.0 ** -8 * jnp.max(jnp.abs(qkv))
+
+    w = jax.random.normal(ks[1], out.shape)
+    grads = [jax.jit(jax.grad(lambda x, f=f: jnp.sum(f(x, heads) * w)))(qkv)
+             for f in (da_ops.dit_attention, da_ref.dit_attention)]
+    assert jnp.array_equal(*grads)
+
+
+@pytest.mark.parametrize("S,precision,engaged", [
+    (17, None, False), (127, None, False), (128, None, True),
+    (257, None, True), (da_ops.MAX_SEQ, None, True),
+    (da_ops.MAX_SEQ + 1, None, False), (257, "highest", False)])
+def test_dit_attention_dispatch_rule(monkeypatch, S, precision, engaged):
+    """The default DiT path takes the kernel on the TPU within
+    [MIN_SEQ, MAX_SEQ] at the default matmul precision, and never on the
+    CPU."""
+    assert not da_ops.engages(S)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with jax.default_matmul_precision(precision):
+        assert da_ops.engages(S) == engaged

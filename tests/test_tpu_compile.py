@@ -10,7 +10,9 @@ has to accept each kernel and keep it as a ``tpu_custom_call``.
 The kernel functions are called with ``interpret=False`` directly: the
 ``ops.py`` wrappers still see the CPU backend here.  The whole fused wave
 program is compiled too, with the wrappers steered to the compiled path
-by the test.
+by the test.  So is the default (``use_pallas=False``) DiT forward,
+with the backend steered to "tpu" by the test, to show where it
+dispatches the ``dit_attention`` kernel.
 """
 import os
 
@@ -20,14 +22,15 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs.oscar import paper_preset
-from repro.diffusion.dit import init_dit
+from repro.configs.oscar import DiffusionConfig, paper_preset
+from repro.diffusion.dit import dit_apply, init_dit
 from repro.diffusion.guidance import ragged_tables
 from repro.diffusion.sampler import _ragged_core
 from repro.diffusion.schedule import make_schedule
 from repro.kernels import compiled_kernels
 from repro.kernels.adaln_norm import kernel as adaln
 from repro.kernels.cfg_fuse import kernel as cfg
+from repro.kernels.dit_attention import kernel as dit_attn
 from repro.kernels.flash_attention import kernel as flash
 
 
@@ -62,7 +65,7 @@ def _compile(fn, sharding, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-F32, I32 = jnp.float32, jnp.int32
+F32, I32, BF16 = jnp.float32, jnp.int32, jnp.bfloat16
 B_CFG, R = 128, 8          # one image = 768 floats -> 6 lane rows, padded to 8
 B_DIT, S, TRUE_S, HEADS, HD, D = 256, 24, 17, 4, 36, 144
 
@@ -84,6 +87,10 @@ CASES = {
             q, k, v, causal=False, blk_q=S, blk_k=S, true_sk=TRUE_S,
             interpret=False),
         [((B_DIT, HEADS, S, HD), F32)] * 3),
+    # b2.round_drain's attention core: 240 sequences of DiT-B/2
+    "dit_attention": (
+        lambda qkv: dit_attn.dit_attention_3d(qkv, heads=12, interpret=False),
+        [((240, 257, 3 * 768), BF16)]),
     "adaln_norm": (
         lambda x, scale, shift: adaln.adaln_norm_3d(x, scale, shift,
                                                     interpret=False),
@@ -130,3 +137,29 @@ def test_fused_wave_program_compiles_for_v5e(one_chip, monkeypatch):
         use_pallas=True).compile().as_text()
     assert compiled_kernels(text) == {"cfg_fuse_rowwise", "flash_attention",
                                       "adaln_norm"}
+
+
+@pytest.mark.parametrize("dc,size,channels,kernels", [
+    (DiffusionConfig(d_model=768, num_layers=1, num_heads=12, patch=2),
+     32, 4, {"dit_attention"}),                       # DiT-B/2, S = 257
+    (paper_preset().diffusion, paper_preset().data.image_size,
+     paper_preset().data.channels, set()),             # S = 17
+])
+def test_default_dit_forward_dispatch_for_v5e(one_chip, monkeypatch, dc, size,
+                                              channels, kernels):
+    """The default path on the TPU runs the attention core through
+    ``dit_attention`` from ``MIN_SEQ`` tokens and keeps the einsum chain
+    below: one 240-sequence forward at DiT-B/2's widths (one block) and
+    at the paper preset's."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    B = 240
+
+    def sds(shape, dt=F32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = jax.tree.map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+        lambda k: init_dit(k, dc, size, channels), jax.random.PRNGKey(0)))
+    text = jax.jit(lambda p, x, t, y: dit_apply(p, dc, x, t, y)).lower(
+        params, sds((B, size, size, channels)), sds((B,), I32),
+        sds((B, dc.cond_dim))).compile().as_text()
+    assert compiled_kernels(text) == kernels
